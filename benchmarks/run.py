@@ -16,6 +16,8 @@ import traceback
 
 def main() -> None:
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (conversion_ablation, fig9_kernel_bench,
                             fig10_schedule_ablation, fig11_e2e_throughput,
                             fig12_same_batch, fmpq_ratio,

@@ -20,8 +20,10 @@ from its shipped RecoveryLog artifacts and promotes it in place.
 
     PYTHONPATH=src python examples/failover_walkthrough.py
 
-The serve CLI drives the same seam:
+The serve CLI drives the same seam, one device per replica (on a CPU,
+force two host devices):
 
+    XLA_FLAGS=--xla_force_host_platform_device_count=2 \
     PYTHONPATH=src python -m repro.launch.serve --arch llama3_8b \
         --smoke --requests 6 --max-new 8 --replicas 2 \
         --failover migrate --kill-replica-at 6 --stream

@@ -18,7 +18,7 @@ The affine pre/post terms (q̃, c, the s_v/z_v epilogue) are O(D) work done
 outside the kernel.
 
 The kernel is a standard online-softmax flash-decode: grid over
-(batch·kv_head, T chunks), running max/денominator in VMEM scratch.
+(batch·kv_head, T chunks), running max/denominator in VMEM scratch.
 """
 
 from __future__ import annotations
@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 __all__ = ["kv4_decode_attention"]
 
@@ -45,8 +44,9 @@ def _unpack_nibbles_f32(packed):
     hi nibbles [D/2, D) — matching `pack_int4_kv` in ops.py (location
     switch along channels so no element interleave is needed).
     """
-    lo = (packed & jnp.uint8(0x0F)).astype(jnp.float32)
-    hi = (packed >> jnp.uint8(4)).astype(jnp.float32)
+    p = packed.astype(jnp.int32)     # Mosaic has no 8-bit shifts/casts
+    lo = (p & 0x0F).astype(jnp.float32)
+    hi = (p >> 4).astype(jnp.float32)
     return jnp.concatenate([lo, hi], axis=-1)
 
 
@@ -165,7 +165,7 @@ def kv4_decode_attention(
             jax.ShapeDtypeStruct((b * hkv, g, d), jnp.float32),
             jax.ShapeDtypeStruct((b * hkv, g, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

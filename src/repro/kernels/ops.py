@@ -79,7 +79,7 @@ def w4ax_matmul(
     schedule: str = "split",     # "split" (optimized) | "mixed" (paper baseline)
     impl: str = "auto",
     bm: int = 128,
-    bn: int = 128,
+    bn: int | None = None,
 ) -> jax.Array:
     """Mixed-precision W4Ax GEMM: out = dequant(a) @ dequant(w). → [..., N] f32."""
     lead = a4_packed.shape[:-1]
@@ -117,7 +117,7 @@ def w4ax_matmul(
     elif schedule == "mixed":
         out = WK.w4ax_matmul_mixed(
             a4p, a4s, a8q, a8s, w_packed, w_scale,
-            bm=eff_bm, bn=bn, interpret=interp,
+            bm=eff_bm, bn=bn or 128, interpret=interp,
         )
     else:
         raise ValueError(f"schedule must be split|mixed, got {schedule}")
@@ -163,7 +163,7 @@ def kv4_decode_attention(
 
 def paged_kv4_decode_attention(
     q: jax.Array,             # [B, Hq, D]
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8
     k_scale: jax.Array,       # [Hkv, 1, D] or [B, Hkv, 1, D]
     k_zero: jax.Array,
     v_pool: jax.Array,
@@ -200,7 +200,7 @@ def paged_kv4_prefill_attention(
     q: jax.Array,             # [B, C, Hq, D] — one prefill chunk's queries
     k_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk keys
     v_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk values
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8
     k_scale: jax.Array,       # [Hkv, 1, D]
     k_zero: jax.Array,
     v_pool: jax.Array,
@@ -235,7 +235,7 @@ def paged_kv4_prefill_attention(
 
 def paged_kv4_decode_attention_wq(
     q: jax.Array,             # [B, Hq, D]
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8
     k_scale: jax.Array,       # [Hkv, 1, D] or [B, Hkv, 1, D]
     k_zero: jax.Array,
     v_pool: jax.Array,
@@ -266,7 +266,7 @@ def paged_kv4_prefill_attention_wq(
     q: jax.Array,             # [B, C, Hq, D] — one prefill chunk's queries
     k_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk keys
     v_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk values
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8
     k_scale: jax.Array,       # [Hkv, 1, D]
     k_zero: jax.Array,
     v_pool: jax.Array,
@@ -298,9 +298,7 @@ def paged_kv4_prefill_attention_wq(
 # Activation quantization
 # ---------------------------------------------------------------------------
 
-def act_quant(
-    x: jax.Array, *, bits: int = 4, impl: str = "auto", bm: int = 256
-):
+def act_quant(x: jax.Array, *, bits: int = 4, impl: str = "auto"):
     """[..., K] float → (payload, scales [..., K/128]).
 
     bits=4 → packed uint8 [..., K/2]; bits=8 → int8 [..., K].
@@ -312,6 +310,7 @@ def act_quant(
     if not use_pallas:
         payload, scale = R.act_quant_ref(x2, block_size=BLOCK_K, bits=bits)
     else:
+        bm = QK.row_block(k)
         x2p, m = _pad_rows(x2, min(bm, max(8, x2.shape[0])))
         eff_bm = min(bm, x2p.shape[0])
         if bits == 4:

@@ -52,8 +52,10 @@ in-kernel (``p@n_v ⊙ s_v − (Σp)·s_v⊙z_v`` — the matmul still runs on
 raw nibbles; the affine is linear in ``p``, so it commutes with the
 combine).
 
-Layout: pools are ``[num_pages, page_size, Hkv, D/2]`` uint8 — one page
-per grid step; dense block tables are ``[B, max_pages]`` int32 with
+Layout: pools are ``[num_pages, Hkv, page_size, D/2]`` uint8, so one
+grid step's block — one page of one kv head — is a whole ``[ps, D/2]``
+tile (Mosaic needs a block's two minor dimensions to be tile-aligned or
+whole); dense block tables are ``[B, max_pages]`` int32 with
 unmapped entries clamped to 0 (masked by length in-kernel, never read
 semantically); work-queue descriptors address physical pages directly.
 """
@@ -67,7 +69,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.kv4_attention import NEG_INF, _unpack_nibbles_f32
 
 __all__ = [
@@ -106,8 +107,8 @@ def _paged_kv4_decode_kernel(
     len_ref,               # scalar prefetch: [B] int32 valid lengths
     qt_ref,                # [1, G, D] f32  — q·s_k/√D (pre-scaled)
     c_ref,                 # [1, G, 1] f32  — zero-point fold Σ q̃·z_k
-    kp_ref,                # [1, ps, 1, D/2] uint8 — one K page
-    vp_ref,                # [1, ps, 1, D/2] uint8 — one V page
+    kp_ref,                # [1, 1, ps, D/2] uint8 — one K page
+    vp_ref,                # [1, 1, ps, D/2] uint8 — one V page
     o_ref,                 # [1, G, D] f32 — unnormalized Σ p̃·n_v
     l_ref,                 # [1, G, 1] f32 — softmax denominator
     acc_ref, m_ref, d_ref, # scratch: [G, D], [G, 1], [G, 1]
@@ -133,7 +134,7 @@ def _paged_kv4_decode_kernel(
     def _compute():
         qt = qt_ref[0]                                 # [G, D]
         c = c_ref[0]                                   # [G, 1]
-        nk = _unpack_nibbles_f32(kp_ref[0, :, 0, :])   # [ps, D]
+        nk = _unpack_nibbles_f32(kp_ref[0, 0])         # [ps, D]
         s = jax.lax.dot_general(
             qt, nk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -145,7 +146,7 @@ def _paged_kv4_decode_kernel(
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                         # [G, ps]
-        nv = _unpack_nibbles_f32(vp_ref[0, :, 0, :])   # [ps, D]
+        nv = _unpack_nibbles_f32(vp_ref[0, 0])         # [ps, D]
         pv = jax.lax.dot_general(
             p, nv, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -162,10 +163,10 @@ def _paged_kv4_decode_kernel(
 
 def paged_kv4_decode_attention(
     q: jax.Array,             # [B, Hq, D] — decode-step queries
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical K pages
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical K pages
     k_scale: jax.Array,       # [Hkv, 1, D] (or [B, Hkv, 1, D]) f32
     k_zero: jax.Array,        # [Hkv, 1, D] f32
-    v_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical V pages
+    v_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical V pages
     v_scale: jax.Array,       # [Hkv, 1, D] f32
     v_zero: jax.Array,        # [Hkv, 1, D] f32
     block_tables: jax.Array,  # [B, NP] int32 physical page per logical page
@@ -175,7 +176,7 @@ def paged_kv4_decode_attention(
 ) -> jax.Array:
     """Flash-decode straight off the paged pools. Returns [B, Hq, D] f32."""
     b, hq, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     g = hq // hkv
     npages = block_tables.shape[1]
     tables = jnp.maximum(block_tables.astype(jnp.int32), 0)
@@ -196,7 +197,7 @@ def paged_kv4_decode_attention(
         _paged_kv4_decode_kernel, ps=ps, npages=npages, hkv=hkv)
 
     def page_map(bh, pi, tbl, lens):
-        return (tbl[bh // hkv, pi], 0, bh % hkv, 0)
+        return (tbl[bh // hkv, pi], bh % hkv, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -204,8 +205,8 @@ def paged_kv4_decode_attention(
         in_specs=[
             pl.BlockSpec((1, g, d), lambda bh, pi, tbl, lens: (bh, 0, 0)),
             pl.BlockSpec((1, g, 1), lambda bh, pi, tbl, lens: (bh, 0, 0)),
-            pl.BlockSpec((1, ps, 1, d // 2), page_map),
-            pl.BlockSpec((1, ps, 1, d // 2), page_map),
+            pl.BlockSpec((1, 1, ps, d // 2), page_map),
+            pl.BlockSpec((1, 1, ps, d // 2), page_map),
         ],
         out_specs=[
             pl.BlockSpec((1, g, d), lambda bh, pi, tbl, lens: (bh, 0, 0)),
@@ -224,7 +225,7 @@ def paged_kv4_decode_attention(
             jax.ShapeDtypeStruct((b * hkv, g, d), jnp.float32),
             jax.ShapeDtypeStruct((b * hkv, g, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -254,8 +255,8 @@ def _paged_kv4_prefill_kernel(
     vn_ref,                # [1, C, D] f32 — in-flight fp chunk values
     vs_ref,                # [1, 1, D] f32 — v_scale (history V dequant)
     vz_ref,                # [1, 1, D] f32 — v_zero
-    kp_ref,                # [1, ps, 1, D/2] uint8 — one K history page
-    vp_ref,                # [1, ps, 1, D/2] uint8 — one V history page
+    kp_ref,                # [1, 1, ps, D/2] uint8 — one K history page
+    vp_ref,                # [1, 1, ps, D/2] uint8 — one V history page
     o_ref,                 # [1, CG, D] f32 — unnormalized output
     l_ref,                 # [1, CG, 1] f32 — softmax denominator
     acc_ref, m_ref, d_ref, # scratch: [CG, D], [CG, 1], [CG, 1]
@@ -294,7 +295,7 @@ def _paged_kv4_prefill_kernel(
     def _history():
         qt = qt_ref[0]                                 # [CG, D]
         cc = c_ref[0]                                  # [CG, 1]
-        nk = _unpack_nibbles_f32(kp_ref[0, :, 0, :])   # [ps, D]
+        nk = _unpack_nibbles_f32(kp_ref[0, 0])         # [ps, D]
         s = jax.lax.dot_general(
             qt, nk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -303,7 +304,7 @@ def _paged_kv4_prefill_kernel(
         s = jnp.where(pos < ctx, s, NEG_INF)
 
         def vals(p):
-            nv = _unpack_nibbles_f32(vp_ref[0, :, 0, :])
+            nv = _unpack_nibbles_f32(vp_ref[0, 0])
             pv = jax.lax.dot_general(
                 p, nv, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -337,10 +338,10 @@ def paged_kv4_prefill_attention(
     q: jax.Array,             # [B, C, Hq, D] — one prefill chunk's queries
     k_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk keys
     v_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk values
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical K pages
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical K pages
     k_scale: jax.Array,       # [Hkv, 1, D] f32
     k_zero: jax.Array,        # [Hkv, 1, D] f32
-    v_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical V pages
+    v_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical V pages
     v_scale: jax.Array,       # [Hkv, 1, D] f32
     v_zero: jax.Array,        # [Hkv, 1, D] f32
     block_tables: jax.Array,  # [B, NP] int32 physical page per logical page
@@ -357,7 +358,7 @@ def paged_kv4_prefill_attention(
     outside). Returns [B, C, Hq, D] f32.
     """
     b, c, hq, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     g = hq // hkv
     npages = block_tables.shape[1]
     tables = jnp.maximum(block_tables.astype(jnp.int32), 0)
@@ -384,7 +385,7 @@ def paged_kv4_prefill_attention(
 
     def page_map(bh, pi, tbl, ctx, qlen):
         return (tbl[bh // hkv, jnp.maximum(jnp.minimum(pi, npages - 1), 0)],
-                0, bh % hkv, 0)
+                bh % hkv, 0, 0)
 
     def row_map(bh, pi, tbl, ctx, qlen):
         return (bh, 0, 0)
@@ -403,8 +404,8 @@ def paged_kv4_prefill_attention(
             pl.BlockSpec((1, c, d), row_map),           # v_new
             pl.BlockSpec((1, 1, d), head_map),          # v_scale
             pl.BlockSpec((1, 1, d), head_map),          # v_zero
-            pl.BlockSpec((1, ps, 1, d // 2), page_map), # K page
-            pl.BlockSpec((1, ps, 1, d // 2), page_map), # V page
+            pl.BlockSpec((1, 1, ps, d // 2), page_map), # K page
+            pl.BlockSpec((1, 1, ps, d // 2), page_map), # V page
         ],
         out_specs=[
             pl.BlockSpec((1, c * g, d), row_map),
@@ -423,7 +424,7 @@ def paged_kv4_prefill_attention(
             jax.ShapeDtypeStruct((b * hkv, c * g, d), jnp.float32),
             jax.ShapeDtypeStruct((b * hkv, c * g, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -444,8 +445,8 @@ def _paged_kv4_decode_wq_kernel(
     desc_ref,              # scalar prefetch: [W, 4] (row, page, count, kind)
     qt_ref,                # [1, G, D] f32 — the item's row q·s_k/√D
     c_ref,                 # [1, G, 1] f32 — zero-point fold Σ q̃·z_k
-    kp_ref,                # [1, ps, 1, D/2] uint8 — the item's K page
-    vp_ref,                # [1, ps, 1, D/2] uint8 — the item's V page
+    kp_ref,                # [1, 1, ps, D/2] uint8 — the item's K page
+    vp_ref,                # [1, 1, ps, D/2] uint8 — the item's V page
     o_ref,                 # [1, G, D] f32 — partial Σ p·n_v (nibble space)
     l_ref,                 # [1, G, 1] f32 — partial denominator Σ p
     m_ref,                 # [1, G, 1] f32 — the item's local max
@@ -454,7 +455,7 @@ def _paged_kv4_decode_wq_kernel(
     count = desc_ref[wi, 2]
 
     qt = qt_ref[0]                                     # [G, D]
-    nk = _unpack_nibbles_f32(kp_ref[0, :, 0, :])       # [ps, D]
+    nk = _unpack_nibbles_f32(kp_ref[0, 0])             # [ps, D]
     s = jax.lax.dot_general(
         qt, nk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -466,7 +467,7 @@ def _paged_kv4_decode_wq_kernel(
     # rows below never reach an output
     m = jnp.max(s, axis=1, keepdims=True)              # [G, 1]
     p = jnp.exp(s - m)
-    nv = _unpack_nibbles_f32(vp_ref[0, :, 0, :])
+    nv = _unpack_nibbles_f32(vp_ref[0, 0])
     o_ref[0] = jax.lax.dot_general(
         p, nv, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -476,10 +477,10 @@ def _paged_kv4_decode_wq_kernel(
 
 def paged_kv4_decode_attention_wq(
     q: jax.Array,             # [B, Hq, D] — decode-step queries
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical K pages
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical K pages
     k_scale: jax.Array,       # [Hkv, 1, D] (or [B, Hkv, 1, D]) f32
     k_zero: jax.Array,        # [Hkv, 1, D] f32
-    v_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical V pages
+    v_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical V pages
     v_scale: jax.Array,       # [Hkv, 1, D] f32
     v_zero: jax.Array,        # [Hkv, 1, D] f32
     work_items: jax.Array,    # [W, 4] int32 (row, phys_page, count, kind)
@@ -489,7 +490,7 @@ def paged_kv4_decode_attention_wq(
     """Work-queue flash-decode: grid = (W,) real-page work items, partial
     (acc, l, m) per item, split-KV combine. Returns [B, Hq, D] f32."""
     b, hq, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     g = hq // hkv
     nrows = b * hkv
     w = work_items.shape[0]
@@ -510,8 +511,8 @@ def paged_kv4_decode_attention_wq(
         return (jnp.minimum(desc[wi, 0], nrows - 1), 0, 0)
 
     def page_map(wi, desc):
-        return (desc[wi, 1], 0,
-                jnp.minimum(desc[wi, 0], nrows - 1) % hkv, 0)
+        return (desc[wi, 1],
+                jnp.minimum(desc[wi, 0], nrows - 1) % hkv, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -519,8 +520,8 @@ def paged_kv4_decode_attention_wq(
         in_specs=[
             pl.BlockSpec((1, g, d), row_map),
             pl.BlockSpec((1, g, 1), row_map),
-            pl.BlockSpec((1, ps, 1, d // 2), page_map),
-            pl.BlockSpec((1, ps, 1, d // 2), page_map),
+            pl.BlockSpec((1, 1, ps, d // 2), page_map),
+            pl.BlockSpec((1, 1, ps, d // 2), page_map),
         ],
         out_specs=[
             pl.BlockSpec((1, g, d), lambda wi, desc: (wi, 0, 0)),
@@ -539,7 +540,7 @@ def paged_kv4_decode_attention_wq(
         # every step writes its own output block — the grid is a
         # divisible pool with no cross-step carry, so the whole axis is
         # parallel (the Stream-K property the dense schedule lacks)
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -562,8 +563,8 @@ def _paged_kv4_prefill_wq_kernel(
     vn_ref,                # [1, C, D] f32 — the row's in-flight fp values
     vs_ref,                # [1, 1, D] f32 — v_scale (history V dequant)
     vz_ref,                # [1, 1, D] f32 — v_zero
-    kp_ref,                # [1, ps, 1, D/2] uint8 — the item's K page
-    vp_ref,                # [1, ps, 1, D/2] uint8 — the item's V page
+    kp_ref,                # [1, 1, ps, D/2] uint8 — the item's K page
+    vp_ref,                # [1, 1, ps, D/2] uint8 — the item's V page
     o_ref,                 # [1, CG, D] f32 — partial numerator (value space)
     l_ref,                 # [1, CG, 1] f32 — partial denominator
     m_ref,                 # [1, CG, 1] f32 — the item's local max
@@ -578,7 +579,7 @@ def _paged_kv4_prefill_wq_kernel(
     @pl.when(kind == 0)
     def _history():
         qt = qt_ref[0]                                 # [CG, D]
-        nk = _unpack_nibbles_f32(kp_ref[0, :, 0, :])   # [ps, D]
+        nk = _unpack_nibbles_f32(kp_ref[0, 0])         # [ps, D]
         s = jax.lax.dot_general(
             qt, nk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -587,7 +588,7 @@ def _paged_kv4_prefill_wq_kernel(
         s = jnp.where(pos < count, s, NEG_INF)
         m = jnp.max(s, axis=1, keepdims=True)
         p = jnp.exp(s - m)
-        nv = _unpack_nibbles_f32(vp_ref[0, :, 0, :])
+        nv = _unpack_nibbles_f32(vp_ref[0, 0])
         pv = jax.lax.dot_general(
             p, nv, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # [CG, D]
@@ -623,10 +624,10 @@ def paged_kv4_prefill_attention_wq(
     q: jax.Array,             # [B, C, Hq, D] — one prefill chunk's queries
     k_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk keys
     v_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk values
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical K pages
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical K pages
     k_scale: jax.Array,       # [Hkv, 1, D] f32
     k_zero: jax.Array,        # [Hkv, 1, D] f32
-    v_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical V pages
+    v_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical V pages
     v_scale: jax.Array,       # [Hkv, 1, D] f32
     v_zero: jax.Array,        # [Hkv, 1, D] f32
     work_items: jax.Array,    # [W, 4] int32 (row, phys_page, count, kind)
@@ -639,7 +640,7 @@ def paged_kv4_prefill_attention_wq(
     past a row's ``q_len`` are padding garbage, mask outside. Returns
     [B, C, Hq, D] f32."""
     b, c, hq, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     g = hq // hkv
     nrows = b * hkv
     w = work_items.shape[0]
@@ -669,8 +670,8 @@ def paged_kv4_prefill_attention_wq(
         return (jnp.minimum(desc[wi, 0], nrows - 1) % hkv, 0, 0)
 
     def page_map(wi, desc):
-        return (desc[wi, 1], 0,
-                jnp.minimum(desc[wi, 0], nrows - 1) % hkv, 0)
+        return (desc[wi, 1],
+                jnp.minimum(desc[wi, 0], nrows - 1) % hkv, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -683,8 +684,8 @@ def paged_kv4_prefill_attention_wq(
             pl.BlockSpec((1, c, d), row_map),           # v_new
             pl.BlockSpec((1, 1, d), head_map),          # v_scale
             pl.BlockSpec((1, 1, d), head_map),          # v_zero
-            pl.BlockSpec((1, ps, 1, d // 2), page_map), # K page
-            pl.BlockSpec((1, ps, 1, d // 2), page_map), # V page
+            pl.BlockSpec((1, 1, ps, d // 2), page_map), # K page
+            pl.BlockSpec((1, 1, ps, d // 2), page_map), # V page
         ],
         out_specs=[
             pl.BlockSpec((1, c * g, d), lambda wi, desc: (wi, 0, 0)),
@@ -700,7 +701,7 @@ def paged_kv4_prefill_attention_wq(
             jax.ShapeDtypeStruct((w, c * g, 1), jnp.float32),
             jax.ShapeDtypeStruct((w, c * g, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -53,11 +53,14 @@ def w4a4_matmul_ref(
     wb = w.reshape(nb, block_size, n).astype(jnp.int32)
     # int32 per-block partial dot: [M, nb, N]
     part = jnp.einsum("mbk,bkn->mbn", ab, wb)
+    # HIGHEST: the rescale is exact f32 on every backend (a TPU's
+    # default f32 dot takes bf16 passes, ~2^-9 relative error)
     out = jnp.einsum(
         "mbn,mb,bn->mn",
         part.astype(jnp.float32),
         a_scale.astype(jnp.float32),
         w_scale.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
     )
     return out
 
@@ -82,6 +85,7 @@ def w4a8_matmul_ref(
         part.astype(jnp.float32),
         a_scale.astype(jnp.float32),
         w_scale.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -164,10 +168,10 @@ def kv4_decode_attention_ref(
 
 def paged_kv4_decode_attention_ref(
     q: jax.Array,             # [B, Hq, D] — decode-step queries
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical K pages
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical K pages
     k_scale: jax.Array,       # [Hkv, 1, D] (or [B, Hkv, 1, D]) f32
     k_zero: jax.Array,        # [Hkv, 1, D] f32
-    v_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical V pages
+    v_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical V pages
     v_scale: jax.Array,       # [Hkv, 1, D] f32
     v_zero: jax.Array,        # [Hkv, 1, D] f32
     block_tables: jax.Array,  # [B, NP] int32 (-1/unmapped → clamped to 0)
@@ -178,14 +182,14 @@ def paged_kv4_decode_attention_ref(
     contiguous oracle. The gather is what the Pallas kernel's block-table
     index_map eliminates; here it *defines* the expected semantics."""
     b, hq, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     npages = block_tables.shape[1]
     tables = jnp.maximum(block_tables.astype(jnp.int32), 0)
 
     def gather(pool):
-        pages = pool[tables]                     # [B, NP, ps, Hkv, D/2]
-        flat = pages.reshape(b, npages * ps, hkv, d // 2)
-        return flat.swapaxes(1, 2)               # [B, Hkv, NP·ps, D/2]
+        pages = pool[tables]                     # [B, NP, Hkv, ps, D/2]
+        return jnp.moveaxis(pages, 2, 1).reshape(
+            b, hkv, npages * ps, d // 2)         # [B, Hkv, NP·ps, D/2]
 
     def bcast(s):
         return jnp.broadcast_to(s, (b, hkv, 1, d))
@@ -201,10 +205,10 @@ def paged_kv4_prefill_attention_ref(
     q: jax.Array,             # [B, C, Hq, D] — one prefill chunk's queries
     k_new: jax.Array,         # [B, C, Hkv, D] fp — the chunk's in-flight keys
     v_new: jax.Array,         # [B, C, Hkv, D] fp — the chunk's in-flight values
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical K pages
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical K pages
     k_scale: jax.Array,       # [Hkv, 1, D] f32
     k_zero: jax.Array,        # [Hkv, 1, D] f32
-    v_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical V pages
+    v_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical V pages
     v_scale: jax.Array,       # [Hkv, 1, D] f32
     v_zero: jax.Array,        # [Hkv, 1, D] f32
     block_tables: jax.Array,  # [B, NP] int32 (-1/unmapped → clamped to 0)
@@ -222,7 +226,7 @@ def paged_kv4_prefill_attention_ref(
     Returns [B, C, Hq, D] f32.
     """
     b, c, hq, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     g = hq // hkv
     npages = block_tables.shape[1]
     t_hist = npages * ps
@@ -232,8 +236,8 @@ def paged_kv4_prefill_attention_ref(
         return jnp.broadcast_to(s, (b, hkv, 1, d))
 
     def gather_deq(pool, scale, zero):
-        pages = pool[tables]                     # [B, NP, ps, Hkv, D/2]
-        flat = pages.reshape(b, t_hist, hkv, d // 2).swapaxes(1, 2)
+        pages = pool[tables]                     # [B, NP, Hkv, ps, D/2]
+        flat = jnp.moveaxis(pages, 2, 1).reshape(b, hkv, t_hist, d // 2)
         return Q.dequantize_kv_channelwise(
             flat, bcast(scale), bcast(zero)).astype(compute_dtype)
 
@@ -267,15 +271,15 @@ def paged_kv4_prefill_attention_ref(
 
 def _wq_item_pages(pool, pages, heads):
     """Gather each work item's page for its kv head → [W, ps, D/2]."""
-    return jax.vmap(lambda p, h: pool[p, :, h])(pages, heads)
+    return jax.vmap(lambda p, h: pool[p, h])(pages, heads)
 
 
 def paged_kv4_decode_attention_wq_ref(
     q: jax.Array,             # [B, Hq, D] — decode-step queries
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical K pages
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical K pages
     k_scale: jax.Array,       # [Hkv, 1, D] (or [B, Hkv, 1, D]) f32
     k_zero: jax.Array,        # [Hkv, 1, D] f32
-    v_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical V pages
+    v_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical V pages
     v_scale: jax.Array,       # [Hkv, 1, D] f32
     v_zero: jax.Array,        # [Hkv, 1, D] f32
     work_items: jax.Array,    # [W, 4] int32 (row, phys_page, count, kind)
@@ -286,7 +290,7 @@ def paged_kv4_decode_attention_wq_ref(
     *defines* the schedule's semantics — Σ real pages of work, combined
     by row segment — independent of how the grid binds items to cores."""
     b, hq, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     g = hq // hkv
     nrows = b * hkv
     desc = jnp.asarray(work_items, jnp.int32)
@@ -326,10 +330,10 @@ def paged_kv4_prefill_attention_wq_ref(
     q: jax.Array,             # [B, C, Hq, D] — one prefill chunk's queries
     k_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk keys
     v_new: jax.Array,         # [B, C, Hkv, D] fp in-flight chunk values
-    k_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical K pages
+    k_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical K pages
     k_scale: jax.Array,       # [Hkv, 1, D] f32
     k_zero: jax.Array,        # [Hkv, 1, D] f32
-    v_pool: jax.Array,        # [P, ps, Hkv, D/2] uint8 physical V pages
+    v_pool: jax.Array,        # [P, Hkv, ps, D/2] uint8 physical V pages
     v_scale: jax.Array,       # [Hkv, 1, D] f32
     v_zero: jax.Array,        # [Hkv, 1, D] f32
     work_items: jax.Array,    # [W, 4] int32 (row, phys_page, count, kind)
@@ -339,7 +343,7 @@ def paged_kv4_prefill_attention_wq_ref(
     ``kind``, then the shared split-KV combine. Rows past a row's q_len
     are padding garbage — mask outside. Returns [B, C, Hq, D] f32."""
     b, c, hq, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
+    hkv, ps = k_pool.shape[1], k_pool.shape[2]
     g = hq // hkv
     cg = c * g
     nrows = b * hkv

@@ -11,7 +11,9 @@ Three schedules are provided:
                      K dimension walks INT4 blocks then INT8 blocks and
                      switches precision per step (`lax.cond`) — the TPU
                      analogue of issuing INT4/INT8 mma tiles to SMs
-                     (Fig. 5b). Used as the §Perf *baseline*.
+                     (Fig. 5b). Used as the §Perf *baseline*; it keeps
+                     one 128-channel block per step, which Mosaic does
+                     not accept, so it runs in interpret mode only.
 ``w4ax_matmul_split``the TPU-native optimized schedule (DESIGN.md §2):
                      FMPQ's channel permutation makes INT8 blocks
                      contiguous at the K tail, so the mixed GEMM is two
@@ -37,6 +39,9 @@ amortized over the [bm,128]×[128,bn] MXU dot — this is the paper's
 
 The naive sign-extension path (``conversion="signext"``, arithmetic
 shifts, no correction) is retained for the Fig. 10-style ablation.
+
+The uniform kernels walk two blocks per grid step; see the note above
+``_w4a4_kernel`` for how the tiles are laid out for Mosaic.
 """
 
 from __future__ import annotations
@@ -48,9 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
-BLOCK_K = 128        # quantization block (channels) == one K grid step
+BLOCK_K = 128        # quantization block (channels)
 PACKED_BLOCK = 64    # bytes per block row-pair (BLOCK_K / 2)
 
 __all__ = [
@@ -95,12 +98,6 @@ def _unpack_signext_rows(packed):
     return jnp.concatenate([lo, hi], axis=0)
 
 
-def _unpack_signext_cols(packed):
-    lo = (packed & jnp.uint8(0x0F)).astype(jnp.int8) - jnp.int8(8)
-    hi = (packed >> jnp.uint8(4)).astype(jnp.int8) - jnp.int8(8)
-    return jnp.concatenate([lo, hi], axis=1)
-
-
 def _int_dot(a, b):
     """int8 × int8 → int32 MXU dot."""
     return jax.lax.dot_general(
@@ -109,136 +106,207 @@ def _int_dot(a, b):
 
 
 # ---------------------------------------------------------------------------
-# Uniform W4A4 kernel
+# Uniform kernels: one grid step = one 128-lane packed tile = two K blocks
 # ---------------------------------------------------------------------------
+#
+# Mosaic tiles the minor dimension in 128 lanes, so a grid step walks
+# STEP_K = 256 channels (two quantization blocks, 128 packed bytes) and
+# handles the two blocks inside the kernel:
+#
+# * weights: rows [64b, 64b+64) of the [128, bn] packed tile hold block b;
+#   its nibble panels concatenate along sublanes into the block's
+#   [128, bn] biased int4 values (the blocked interleave, unchanged);
+# * int4 activations: lanes [64b, 64b+64) of the [bm, 128] packed tile
+#   hold block b. A half-width lane rotation puts the other block's bytes
+#   under the same lanes, so block b's [bm, 128] operand is one select
+#   between the low nibbles of one tile and the high nibbles of the other;
+# * scales arrive as whole rows (activation [bm, KB], weight [KB, bn]) and
+#   each block picks its own column / row inside the kernel.
+#
+# Nibble arithmetic runs in int32 (the VPU has no 8-bit shifts); the MXU
+# dot takes int8 operands and accumulates in int32, exactly as before.
 
-def _w4a4_kernel(a_ref, asc_ref, w_ref, wsc_ref, o_ref, acc_ref, *, nsteps, conversion):
+STEP_K = 2 * BLOCK_K     # channels per grid step
+STEP_PACKED = STEP_K // 2
+
+
+def _nibbles_i32(packed):
+    """uint8 → (low, high) nibble planes as int32 (values 0..15)."""
+    p = packed.astype(jnp.int32)
+    return p & 0x0F, p >> 4
+
+
+def _weight_block(w_ref, b):
+    """Block b of the [128, bn] packed weight tile → biased [128, bn] int32."""
+    lo, hi = _nibbles_i32(w_ref[b * PACKED_BLOCK:(b + 1) * PACKED_BLOCK, :])
+    return jnp.concatenate([lo, hi], axis=0)
+
+
+def _act4_blocks(a_ref):
+    """[bm, 128] packed int4 activations → the two blocks' biased
+    [bm, 128] int32 operands, channel order matching ``_weight_block``."""
+    a = a_ref[...].astype(jnp.int32)
+    r = pltpu.roll(a, PACKED_BLOCK, 1)        # half turn: either direction
+    first = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1) < PACKED_BLOCK
+    return (jnp.where(first, a & 0x0F, r >> 4),
+            jnp.where(first, r & 0x0F, a >> 4))
+
+
+def _block_scale(asc_ref, wsc_ref, idx, w_row):
+    """Per-block rescale [bm, bn] = a_scale[:, idx] · w_scale[w_row, :]."""
+    asc = asc_ref[...]
+    col = jax.lax.broadcasted_iota(jnp.int32, asc.shape, 1)
+    sa = jnp.sum(jnp.where(col == idx, asc, 0.0), axis=1, keepdims=True)
+    sw = wsc_ref[pl.ds(w_row, 1), :]
+    return sa * sw
+
+
+def _w4a4_kernel(a_ref, asc_ref, w_ref, wsc_ref, o_ref, acc_ref, *,
+                 nsteps, k_off, conversion):
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if conversion == "zeroext":
-        a = _unpack_zeroext_cols(a_ref[...])       # [bm, 128] biased
-        w = _unpack_zeroext_rows(w_ref[...])       # [128, bn] biased
-        d = _int_dot(a, w)                         # D' int32
-        ra = jnp.sum(a.astype(jnp.int32), axis=1, keepdims=True)   # Σa' [bm,1]
-        cw = jnp.sum(w.astype(jnp.int32), axis=0, keepdims=True)   # Σw' [1,bn]
-        d = d - 8 * ra - 8 * cw + (8 * 8 * BLOCK_K)
-    else:
-        a = _unpack_signext_cols(a_ref[...])
-        w = _unpack_signext_rows(w_ref[...])
-        d = _int_dot(a, w)
-
-    scale = asc_ref[...].astype(jnp.float32) * wsc_ref[...].astype(jnp.float32)
-    acc_ref[...] += d.astype(jnp.float32) * scale
+    for b, a in enumerate(_act4_blocks(a_ref)):   # biased [bm, 128]
+        w = _weight_block(w_ref, b)                 # biased [128, bn]
+        if conversion == "zeroext":
+            d = _int_dot(a.astype(jnp.int8), w.astype(jnp.int8))
+            ra = jnp.sum(a, axis=1, keepdims=True)  # Σa' [bm,1]
+            cw = jnp.sum(w, axis=0, keepdims=True)  # Σw' [1,bn]
+            d = d - 8 * ra - 8 * cw + (8 * 8 * BLOCK_K)
+        else:
+            d = _int_dot((a - 8).astype(jnp.int8), (w - 8).astype(jnp.int8))
+        idx = 2 * ki + b
+        scale = _block_scale(asc_ref, wsc_ref, idx, k_off + idx)
+        acc_ref[...] += d.astype(jnp.float32) * scale
 
     @pl.when(ki == nsteps - 1)
     def _done():
         o_ref[...] = acc_ref[...]
+
+
+def _w4a8_kernel(a_ref, asc_ref, w_ref, wsc_ref, o_ref, acc_ref, *,
+                 nsteps, k_off, conversion):
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    for b in range(2):
+        a = a_ref[:, b * BLOCK_K:(b + 1) * BLOCK_K]   # [bm, 128] int8 (true)
+        w = _weight_block(w_ref, b)                     # biased [128, bn]
+        if conversion == "zeroext":
+            d = _int_dot(a, w.astype(jnp.int8))
+            ra = jnp.sum(a.astype(jnp.int32), axis=1, keepdims=True)  # Σa
+            d = d - 8 * ra
+        else:
+            d = _int_dot(a, (w - 8).astype(jnp.int8))
+        idx = 2 * ki + b
+        scale = _block_scale(asc_ref, wsc_ref, idx, k_off + idx)
+        acc_ref[...] += d.astype(jnp.float32) * scale
+
+    @pl.when(ki == nsteps - 1)
+    def _done():
+        o_ref[...] = acc_ref[...]
+
+
+def _pick_bn(n: int) -> int:
+    for c in (512, 256, 128):
+        if n % c == 0:
+            return c
+    return n if n < 128 else 128
+
+
+def _whole_steps(a, a_scale, w_packed, w_scale, k_off, a_pad_lanes, a_fill):
+    """Bring one uniform part to whole 256-channel steps.
+
+    The fast path reads the part's weight rows in place at ``k_off``
+    blocks. An odd block count, or an odd offset, slices the part's
+    weights out and pads every operand by one block whose activation
+    scale is 0, so the pad contributes exactly 0 to the sum."""
+    kb = a_scale.shape[1]
+    if kb % 2 == 0 and k_off % 2 == 0:
+        return a, a_scale, w_packed, w_scale, k_off
+    w_packed = w_packed[k_off * PACKED_BLOCK:(k_off + kb) * PACKED_BLOCK]
+    w_scale = w_scale[k_off:k_off + kb]
+    if kb % 2:
+        a = jnp.pad(a, ((0, 0), (0, a_pad_lanes)), constant_values=a_fill)
+        a_scale = jnp.pad(a_scale, ((0, 0), (0, 1)))
+        w_packed = jnp.pad(w_packed, ((0, PACKED_BLOCK), (0, 0)),
+                           constant_values=0x88)
+        w_scale = jnp.pad(w_scale, ((0, 1), (0, 0)))
+    return a, a_scale, w_packed, w_scale, 0
+
+
+def _uniform_call(kernel, a, a_scale, w_packed, w_scale, a_block_k, *,
+                  k_off, bm, bn, conversion, interpret):
+    m = a.shape[0]
+    n = w_packed.shape[1]
+    kb = a_scale.shape[1]
+    nsteps = kb // 2
+    bn = _pick_bn(n) if bn is None else bn
+    step0 = k_off // 2
+    return pl.pallas_call(
+        functools.partial(kernel, nsteps=nsteps, k_off=k_off,
+                          conversion=conversion),
+        grid=(pl.cdiv(m, bm), pl.cdiv(n, bn), nsteps),
+        in_specs=[
+            pl.BlockSpec((bm, a_block_k), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bm, kb), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((STEP_PACKED, bn), lambda i, j, k: (step0 + k, j)),
+            pl.BlockSpec((w_scale.shape[0], bn), lambda i, j, k: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(a, a_scale, w_packed, w_scale)
 
 
 def w4a4_matmul(
     a_packed: jax.Array,   # [M, K/2] uint8 (blocked interleave, biased)
     a_scale: jax.Array,    # [M, K/128] f32
-    w_packed: jax.Array,   # [K/2, N] uint8
-    w_scale: jax.Array,    # [K/128, N] f32
+    w_packed: jax.Array,   # [>= (k_off + K/128)·64, N] uint8
+    w_scale: jax.Array,    # [>= k_off + K/128, N] f32
     *,
+    k_off: int = 0,        # first weight block of this part
     bm: int = 128,
-    bn: int = 128,
+    bn: int | None = None,
     conversion: str = "zeroext",
     interpret: bool = False,
 ) -> jax.Array:
-    m = a_packed.shape[0]
-    n = w_packed.shape[1]
-    kb = a_scale.shape[1]                      # number of 128-channel blocks
-    assert a_packed.shape[1] == kb * PACKED_BLOCK
-    assert w_packed.shape[0] == kb * PACKED_BLOCK
-    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), kb)
-
-    kernel = functools.partial(_w4a4_kernel, nsteps=kb, conversion=conversion)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, PACKED_BLOCK), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, k)),
-            pl.BlockSpec((PACKED_BLOCK, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(a_packed, a_scale, w_packed, w_scale)
-
-
-# ---------------------------------------------------------------------------
-# Uniform W4A8 kernel (fast INT4→INT8 conversion for the weights)
-# ---------------------------------------------------------------------------
-
-def _w4a8_kernel(a_ref, asc_ref, w_ref, wsc_ref, o_ref, acc_ref, *, nsteps, conversion):
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    a = a_ref[...]                                  # [bm, 128] int8 (true values)
-    if conversion == "zeroext":
-        w = _unpack_zeroext_rows(w_ref[...])        # [128, bn] biased
-        d = _int_dot(a, w)
-        ra = jnp.sum(a.astype(jnp.int32), axis=1, keepdims=True)    # Σa
-        d = d - 8 * ra                              # dot(a, w'+? ) − 8Σa
-    else:
-        w = _unpack_signext_rows(w_ref[...])
-        d = _int_dot(a, w)
-
-    scale = asc_ref[...].astype(jnp.float32) * wsc_ref[...].astype(jnp.float32)
-    acc_ref[...] += d.astype(jnp.float32) * scale
-
-    @pl.when(ki == nsteps - 1)
-    def _done():
-        o_ref[...] = acc_ref[...]
+    assert a_packed.shape[1] == a_scale.shape[1] * PACKED_BLOCK
+    a_packed, a_scale, w_packed, w_scale, k_off = _whole_steps(
+        a_packed, a_scale, w_packed, w_scale, k_off, PACKED_BLOCK, 0x88)
+    return _uniform_call(_w4a4_kernel, a_packed, a_scale, w_packed, w_scale,
+                         STEP_PACKED, k_off=k_off, bm=bm, bn=bn,
+                         conversion=conversion, interpret=interpret)
 
 
 def w4a8_matmul(
     a_q: jax.Array,        # [M, K] int8
     a_scale: jax.Array,    # [M, K/128] f32
-    w_packed: jax.Array,   # [K/2, N] uint8
-    w_scale: jax.Array,    # [K/128, N] f32
+    w_packed: jax.Array,   # [>= (k_off + K/128)·64, N] uint8
+    w_scale: jax.Array,    # [>= k_off + K/128, N] f32
     *,
+    k_off: int = 0,
     bm: int = 128,
-    bn: int = 128,
+    bn: int | None = None,
     conversion: str = "zeroext",
     interpret: bool = False,
 ) -> jax.Array:
-    m, k = a_q.shape
-    n = w_packed.shape[1]
-    kb = k // BLOCK_K
-    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), kb)
-    kernel = functools.partial(_w4a8_kernel, nsteps=kb, conversion=conversion)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, BLOCK_K), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, k)),
-            pl.BlockSpec((PACKED_BLOCK, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(a_q, a_scale, w_packed, w_scale)
+    assert a_q.shape[1] == a_scale.shape[1] * BLOCK_K
+    a_q, a_scale, w_packed, w_scale, k_off = _whole_steps(
+        a_q, a_scale, w_packed, w_scale, k_off, BLOCK_K, 0)
+    return _uniform_call(_w4a8_kernel, a_q, a_scale, w_packed, w_scale,
+                         STEP_K, k_off=k_off, bm=bm, bn=bn,
+                         conversion=conversion, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -353,7 +421,7 @@ def w4ax_matmul_mixed(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -373,7 +441,7 @@ def w4ax_matmul_split(
     w_scale: jax.Array,
     *,
     bm: int = 128,
-    bn: int = 128,
+    bn: int | None = None,
     conversion: str = "zeroext",
     interpret: bool = False,
 ) -> jax.Array:
@@ -382,19 +450,19 @@ def w4ax_matmul_split(
     Load-balanced by construction: every grid step of each sub-kernel
     does identical work, so no "SM" ever waits on a slower-precision
     neighbour — the static realization of the paper's tile remapping +
-    Stream-K decomposition (§4.4).
+    Stream-K decomposition (§4.4). Both parts read the one packed weight
+    in place: the W4A8 part starts ``nb4`` blocks in.
     """
     nb4 = a4_scale.shape[1] if a4_packed.shape[1] else 0
-    k4p = nb4 * PACKED_BLOCK
     out = None
     if nb4 > 0:
         out = w4a4_matmul(
-            a4_packed, a4_scale, w_packed[:k4p], w_scale[:nb4],
+            a4_packed, a4_scale, w_packed, w_scale,
             bm=bm, bn=bn, conversion=conversion, interpret=interpret,
         )
     if a8_q.shape[1] > 0:
         o8 = w4a8_matmul(
-            a8_q, a8_scale, w_packed[k4p:], w_scale[nb4:],
+            a8_q, a8_scale, w_packed, w_scale, k_off=nb4,
             bm=bm, bn=bn, conversion=conversion, interpret=interpret,
         )
         out = o8 if out is None else out + o8

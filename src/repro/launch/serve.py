@@ -65,6 +65,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, parse_mesh_arg
 from repro.models.lm import LM, QuantConfig
 from repro.serving.engine import Engine, EngineConfig, SamplingParams
@@ -103,12 +104,12 @@ def _run_group(args, cfg, qparams, qaxes, quant, model: int):
     from repro.serving.faults import Fault, FaultInjector
     from repro.serving.replication import ReplicaGroup
 
-    meshes = None
-    if model > 1:
-        meshes = make_replica_meshes(args.replicas, model)
-        print(f"[mesh] {args.replicas} replica(s) x (data=1, "
-              f"model={model}) over {jax.device_count()} "
-              f"{jax.default_backend()} device(s)", flush=True)
+    # every replica gets its own device slice, model=1 included —
+    # otherwise all replicas' params and pools land on device 0
+    meshes = make_replica_meshes(args.replicas, model)
+    print(f"[mesh] {args.replicas} replica(s) x (data=1, "
+          f"model={model}) over {jax.device_count()} "
+          f"{jax.default_backend()} device(s)", flush=True)
     faults = []
     for i in range(args.replicas):
         inj = (FaultInjector.from_spec(args.inject_faults)
@@ -191,6 +192,20 @@ def _run_group(args, cfg, qparams, qaxes, quant, model: int):
     for idx, why, step in group.deaths:
         print(f"[death] replica {idx} at engine step {step} ({why})",
               flush=True)
+    if not args.inject_faults and not args.kill_replica_at:
+        _exit_on_failures(c["internal_errors"],
+                          sum(r.engine.failed_count for r in live),
+                          [r.engine.last_error for r in live])
+
+
+def _exit_on_failures(internal_errors: int, failed: int, last_errors):
+    """With no faults armed, a quarantined or failed request means the
+    forward itself broke: exit non-zero instead of reporting success."""
+    if internal_errors or failed:
+        errs = "; ".join(e for e in last_errors if e) or "n/a"
+        raise SystemExit(
+            f"[error] failures with no faults armed: failed={failed} "
+            f"internal_errors={internal_errors} last_error={errs}")
 
 
 def main():
@@ -205,7 +220,9 @@ def main():
     ap.add_argument("--page-size", type=int, default=32)
     ap.add_argument("--int4-fraction", type=float, default=0.875)
     ap.add_argument("--schedule", default="split", choices=["split", "mixed"])
-    ap.add_argument("--impl", default="ref", choices=["auto", "pallas", "ref"])
+    ap.add_argument("--impl", default="auto", choices=["auto", "pallas", "ref"],
+                    help="kernel implementation: auto = compiled Pallas on "
+                         "TPU, the jnp reference elsewhere")
     ap.add_argument("--temperature", type=float, default=0.0,
                     help="per-request SamplingParams.temperature (0=greedy)")
     ap.add_argument("--top-k", type=int, default=40,
@@ -292,8 +309,9 @@ def main():
                     help="run N engine replicas behind a ReplicaGroup "
                          "(params replicated, page pools + scheduler "
                          "per-replica; least-loaded routing, per-step "
-                         "health checks, failover). With --mesh 1xM each "
-                         "replica shards over its own M-device slice")
+                         "health checks, failover). Each replica owns its "
+                         "own device, or with --mesh 1xM its own M-device "
+                         "slice; N·M devices must exist")
     ap.add_argument("--failover", default="migrate",
                     choices=["standby", "migrate"],
                     help="replica-death policy: promote a standby engine "
@@ -314,20 +332,18 @@ def main():
                          "--smoke --mesh 1x2")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.head_dim:
         cfg = dataclasses.replace(cfg, head_dim=args.head_dim)
     quant = QuantConfig(int4_fraction=args.int4_fraction,
                         schedule=args.schedule, impl=args.impl)
-    lm_fp = LM(cfg)
     lm_q = LM(cfg, quant=quant)
 
     print(f"[init+quantize] {cfg.name} "
           f"(~{cfg.param_count()/1e6:.1f}M params)", flush=True)
-    params, axes = lm_fp.init(jax.random.PRNGKey(args.seed))
-    qparams, qaxes = lm_q.quantize(params, axes)
-    del params
+    qparams, qaxes = lm_q.init_quantized(jax.random.PRNGKey(args.seed))
 
     data, model = parse_mesh_arg(args.mesh)
     if args.replicas > 1:
@@ -478,6 +494,9 @@ def main():
     for r in finished[:4]:
         print(f"  req {r.request_id}: {r.state.value:9s} "
               f"{r.generated[:12]}…", flush=True)
+    if not eng.faults.faults:
+        _exit_on_failures(eng.internal_errors, eng.failed_count,
+                          [eng.last_error])
 
 
 if __name__ == "__main__":

@@ -221,36 +221,64 @@ class LM:
             lh["w"] = lh["w"].astype(jnp.bfloat16)
             params["lm_head"] = lh
 
-        def transform(p, a):
-            if not isinstance(p, dict):
-                return p, a
-            out_p, out_a = {}, {}
-            for key, val in p.items():
-                quantizable = (
-                    key in QUANT_KEYS and isinstance(val, dict) and "w" in val
-                    and val["w"].shape[-2] % QL.BLOCK_K == 0
-                )
-                if quantizable:
-                    w = val["w"]
-                    lead = w.shape[:-2]
-                    k, n = w.shape[-2:]
-                    w2 = w.reshape(-1, k, n)
-                    packed, scale = jax.vmap(
-                        lambda wi: _quant_one(wi, qcfg))(w2)
-                    packed = packed.reshape(*lead, k // 2, n)
-                    scale = scale.reshape(*lead, k // QL.BLOCK_K, n)
-                    nd = {"w_packed": packed, "w_scale": scale}
-                    na = {"w_packed": a[key]["w"], "w_scale": a[key]["w"]}
-                    if "b" in val:
-                        nd["b"], na["b"] = val["b"], a[key]["b"]
-                    out_p[key], out_a[key] = nd, na
-                elif isinstance(val, dict):
-                    out_p[key], out_a[key] = transform(val, a[key])
-                else:
-                    out_p[key], out_a[key] = val, a[key]
-            return out_p, out_a
+        return _quantize_tree(params, axes, qcfg)
 
-        return transform(params, axes)
+    def init_quantized(self, key):
+        """Seeded random serving params built without the f32 model:
+        ``(qparams, qaxes)`` equal to ``quantize(*init(key))`` (packed
+        payloads bit-equal, weight scales within one f32 ulp — ``jit``
+        may fold the scale division into a reciprocal multiply).
+
+        Dense and MoE stacks are initialised and quantised one layer at
+        a time under ``jit`` and written into a preallocated packed
+        stack, so the device holds the packed model plus one f32 layer
+        at most — a full-width 8B model never exists in f32. Other
+        families fall back to ``quantize(*init(key))``."""
+        cfg = self.cfg
+        assert self.quant is not None
+        if cfg.family not in ("dense", "moe"):
+            return self.quantize(*self.init(key))
+        qcfg = self.quant
+        keys = jax.random.split(key, 8)
+        lkeys = jax.random.split(keys[2], max(cfg.num_layers, 1))
+        init_block = (self._init_dense_block if cfg.family == "dense"
+                      else self._init_moe_block)
+        block_axes = {}
+
+        def packed_block(k):
+            p, a = C.split_annotations(init_block(k))
+            qp, block_axes["axes"] = _quantize_tree(p, a, qcfg)
+            return qp
+
+        layer = jax.jit(packed_block)
+        shapes = jax.eval_shape(packed_block, lkeys[0])
+        blocks = jax.tree.map(
+            lambda s: jnp.zeros((cfg.num_layers,) + s.shape, s.dtype), shapes)
+        donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
+        put = jax.jit(lambda stack, one, i: jax.tree.map(
+            lambda a, b: a.at[i].set(b), stack, one), donate_argnums=donate)
+        for i in range(cfg.num_layers):
+            blocks = put(blocks, layer(lkeys[i]), i)
+
+        def bf16_leaf(init):
+            return jax.jit(lambda k: init(k).value.astype(jnp.bfloat16))
+
+        params = {"embed": {"table": bf16_leaf(
+            lambda k: C.init_embedding(k, cfg.vocab_size,
+                                       cfg.d_model)["table"])(keys[0])}}
+        axes = {"embed": {"table": ("vocab", "embed")}}
+        params["final_norm"], axes["final_norm"] = C.split_annotations(
+            C.init_norm(cfg.norm, cfg.d_model))
+        if not cfg.tie_embeddings:
+            params["lm_head"] = {"w": bf16_leaf(lambda k: C.init_linear(
+                k, cfg.d_model, cfg.vocab_size, ("embed", "vocab"))["w"])(
+                    keys[1])}
+            axes["lm_head"] = {"w": ("embed", "vocab")}
+        params["blocks"] = blocks
+        axes["blocks"] = jax.tree.map(lambda a: ("layers",) + a,
+                                      block_axes["axes"],
+                                      is_leaf=lambda a: isinstance(a, tuple))
+        return params, axes
 
     # ------------------------------------------------------- block pieces
 
@@ -642,6 +670,37 @@ class LM:
 # ---------------------------------------------------------------------------
 # helpers
 # ---------------------------------------------------------------------------
+
+def _quantize_tree(p, a, qcfg: QuantConfig):
+    """Replace every quantizable projection ``{"w": [..., K, N]}`` in the
+    (params, axes) trees by its packed W4 payload."""
+    if not isinstance(p, dict):
+        return p, a
+    out_p, out_a = {}, {}
+    for key, val in p.items():
+        quantizable = (
+            key in QUANT_KEYS and isinstance(val, dict) and "w" in val
+            and val["w"].shape[-2] % QL.BLOCK_K == 0
+        )
+        if quantizable:
+            w = val["w"]
+            lead = w.shape[:-2]
+            k, n = w.shape[-2:]
+            w2 = w.reshape(-1, k, n)
+            packed, scale = jax.vmap(lambda wi: _quant_one(wi, qcfg))(w2)
+            packed = packed.reshape(*lead, k // 2, n)
+            scale = scale.reshape(*lead, k // QL.BLOCK_K, n)
+            nd = {"w_packed": packed, "w_scale": scale}
+            na = {"w_packed": a[key]["w"], "w_scale": a[key]["w"]}
+            if "b" in val:
+                nd["b"], na["b"] = val["b"], a[key]["b"]
+            out_p[key], out_a[key] = nd, na
+        elif isinstance(val, dict):
+            out_p[key], out_a[key] = _quantize_tree(val, a[key], qcfg)
+        else:
+            out_p[key], out_a[key] = val, a[key]
+    return out_p, out_a
+
 
 def _quant_one(w, qcfg: QuantConfig):
     qp, _ = QL.quantize_linear_fraction(

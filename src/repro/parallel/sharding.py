@@ -165,7 +165,7 @@ def cache_pspecs(cache_tree, mesh: Mesh, *, seq_parallel: bool = False,
       vlm cross_kv: k/v [L, B, T_img, Hkv, D]
       length [L, B]
       paged q4 (the serving engine's live pools):
-            k_pool/v_pool [L, P, ps, Hkv, D/2] — kv heads over "model"
+            k_pool/v_pool [L, P, Hkv, ps, D/2] — kv heads over "model"
             (page identity is host-global; every shard holds the full
             page set for its head slice), and their static per-channel
             k_scale/k_zero/v_scale/v_zero [Hkv, 1, D] sharded to match
@@ -205,12 +205,12 @@ def cache_pspecs(cache_tree, mesh: Mesh, *, seq_parallel: bool = False,
             return None
 
         if name in ("k_pool", "v_pool"):
-            # paged serving pools [L, P, ps, Hkv, D/2]: ONLY the kv-head
+            # paged serving pools [L, P, Hkv, ps, D/2]: ONLY the kv-head
             # dim shards — pages are a host-global namespace (the block
             # tables and work-queue descriptors index physical pages
             # identically on every shard)
-            h_ax = _dim_axis(shape[3], mesh, "model")
-            return P(None, None, None, h_ax, None)
+            h_ax = _dim_axis(shape[2], mesh, "model")
+            return P(None, None, h_ax, None, None)
         if name in ("k_packed", "v_packed"):
             # [L, B, Hkv, T, D/2]
             h_ax = _dim_axis(shape[2], mesh, "model")

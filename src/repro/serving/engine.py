@@ -195,7 +195,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -410,6 +409,7 @@ class Engine:
         # rebuilt by __init__ / only meaningful in-process
         "lm", "params", "donate_pools", "_fwd", "_fwd_shapes",
         "_sample_fns", "_gather_bcast", "_param_pspecs", "_scale_pspec",
+        "_pool_pspec", "tp_size",
         "_events", "draft_source",
         # per-process observability counters
         "peak_prefill_fp_tokens", "interleaved_steps", "forward_calls",
@@ -548,6 +548,11 @@ class Engine:
         self._scale_pspec = None
         if self.tp_size > 1:
             self._init_sharding(param_axes)
+        elif mesh is not None:
+            # a one-device mesh (one replica's slice): commit params and
+            # pools to that device, so replicas never share device 0
+            self.params = jax.device_put(self.params, self._pool_sharding())
+            self._place_pools()
         self._fwd = jax.jit(
             self._unified_forward,
             static_argnums=argnums_of(self._unified_forward,
@@ -625,12 +630,25 @@ class Engine:
              "v_scale": cache.v_scale, "v_zero": cache.v_zero}, mesh)
         self._pool_pspec = cspecs["k_pool"]
         self._scale_pspec = cspecs["k_scale"]
-        cache.k_pool = put(cache.k_pool, cspecs["k_pool"])
-        cache.v_pool = put(cache.v_pool, cspecs["v_pool"])
+        self._place_pools()
         cache.k_scale = put(cache.k_scale, cspecs["k_scale"])
         cache.k_zero = put(cache.k_zero, cspecs["k_zero"])
         cache.v_scale = put(cache.v_scale, cspecs["v_scale"])
         cache.v_zero = put(cache.v_zero, cspecs["v_zero"])
+
+    def _pool_sharding(self):
+        if self.tp_size > 1:
+            return NamedSharding(self.mesh, self._pool_pspec)
+        return jax.sharding.SingleDeviceSharding(self.mesh.devices.flat[0])
+
+    def _place_pools(self):
+        """Lay the KV pools out on the engine's mesh (no-op without one):
+        kv-head sharded under TP, else on the mesh's single device."""
+        if self.mesh is None:
+            return
+        sharding = self._pool_sharding()
+        self.cache.k_pool = jax.device_put(self.cache.k_pool, sharding)
+        self.cache.v_pool = jax.device_put(self.cache.v_pool, sharding)
 
     # ----------------------------------------------------- lifecycle API
 
@@ -809,13 +827,8 @@ class Engine:
                 state["sched"], ecfg.max_batch, ecfg.max_batch * 2,
                 max_waiting=ecfg.max_waiting)
             eng.cache.restore_state(state["cache"])
-            if eng.tp_size > 1:
-                # restore_state loads host pools; re-lay them out over
-                # the mesh (kv-head sharding) for the sharded forward
-                put = lambda a: jax.device_put(
-                    a, NamedSharding(eng.mesh, eng._pool_pspec))
-                eng.cache.k_pool = put(eng.cache.k_pool)
-                eng.cache.v_pool = put(eng.cache.v_pool)
+            # restore_state loads host pools; re-lay them out on the mesh
+            eng._place_pools()
             eng.steps = state["steps"]
             eng.tokens_generated = state["tokens_generated"]
             eng._next_id = state["next_id"]
@@ -1560,12 +1573,12 @@ class Engine:
         body = functools.partial(self._unified_body, cmax, no_history,
                                  schedule, "model")
         pool, scale = self._pool_pspec, self._scale_pspec
-        return shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(self._param_pspecs, pool, pool) + (P(),) * 12
             + (scale,) * 4,
             out_specs=(P(), pool, pool),
-            check_rep=False)(*args)
+            check_vma=False)(*args)
 
     def _unified_body(self, cmax: int, no_history: bool, schedule: str,
                       tp_axis, params, k_pool, v_pool, tokens, positions,
@@ -1602,8 +1615,8 @@ class Engine:
                 hkv, half = kq.shape[1], kq.shape[-1]  # [1, Hloc, Tb, D/2]
                 kq = jnp.moveaxis(kq, 1, 2).reshape(-1, hkv, half)
                 vq = jnp.moveaxis(vq, 1, 2).reshape(-1, hkv, half)
-                k_pool = k_pool.at[li, pages, offs].set(kq, mode="drop")
-                v_pool = v_pool.at[li, pages, offs].set(vq, mode="drop")
+                k_pool = k_pool.at[li, pages, :, offs].set(kq, mode="drop")
+                v_pool = v_pool.at[li, pages, :, offs].set(vq, mode="drop")
                 # decode rows' self-attention reads the fake-quantized
                 # chunk — the same values their int4 page dequantizes to
                 kdq, vdq = KVC.qdq_kv_with(

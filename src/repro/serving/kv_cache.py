@@ -1,7 +1,9 @@
 """Paged KV4 cache — vLLM-style block tables over packed int4 storage.
 
-The physical pool is ``[num_pages, page_size, Hkv, D/2]`` uint8 per layer
-stack (one K pool + one V pool, layers stacked on the leading axis).
+The physical pool is ``[num_pages, Hkv, page_size, D/2]`` uint8 per layer
+stack (one K pool + one V pool, layers stacked on the leading axis): one
+page of one kv head is a contiguous ``[page_size, D/2]`` tile, the block
+the paged attention kernels fetch per grid step.
 Sequences own pages through a block table ``[max_seqs, max_pages]`` int32
 (-1 = unmapped) plus an O(1) per-sequence page count maintained by the
 allocator (no row scans on the hot path). Appending a token touches
@@ -234,7 +236,7 @@ class PagedKV4Cache:
         self.cfg = cfg
         self.pcfg = pcfg
         hkv, d = cfg.num_kv_heads, cfg.head_dim
-        shape = (num_layer_slots, pcfg.num_pages, pcfg.page_size, hkv, d // 2)
+        shape = (num_layer_slots, pcfg.num_pages, hkv, pcfg.page_size, d // 2)
         self.k_pool = jnp.zeros(shape, jnp.uint8)
         self.v_pool = jnp.zeros(shape, jnp.uint8)
 
@@ -555,11 +557,9 @@ class PagedKV4Cache:
         pad = need * ps - t
         kp = jnp.pad(kp, ((0, 0), (0, 0), (0, pad), (0, 0)))
         vp = jnp.pad(vp, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        # [Hkv, need, ps, D/2] → per page [ps, Hkv, D/2]
+        # [Hkv, need, ps, D/2] → per page [Hkv, ps, D/2]
         kp = kp[0].reshape(kp.shape[1], need, ps, -1).swapaxes(0, 1)
         vp = vp[0].reshape(vp.shape[1], need, ps, -1).swapaxes(0, 1)
-        kp = kp.swapaxes(1, 2)                              # [need, ps, Hkv, D/2]
-        vp = vp.swapaxes(1, 2)
         pages = self.block_table[seq_id, :need]
         self.k_pool = self.k_pool.at[layer_slot, pages].set(kp)
         self.v_pool = self.v_pool.at[layer_slot, pages].set(vp)
@@ -576,9 +576,9 @@ class PagedKV4Cache:
         ps = self.pcfg.page_size
         page = int(self.block_table[seq_id, ln // ps])
         off = ln % ps
-        self.k_pool = self.k_pool.at[layer_slot, page, off].set(
+        self.k_pool = self.k_pool.at[layer_slot, page, :, off].set(
             kp[0, :, 0, :])
-        self.v_pool = self.v_pool.at[layer_slot, page, off].set(
+        self.v_pool = self.v_pool.at[layer_slot, page, :, off].set(
             vp[0, :, 0, :])
 
     def token_dests_np(self, seq_ids, positions):
@@ -618,8 +618,8 @@ class PagedKV4Cache:
         hkv, half = kq.shape[1], kq.shape[-1]
         kq = jnp.moveaxis(kq, 1, 2).reshape(-1, hkv, half)   # [N, Hkv, D/2]
         vq = jnp.moveaxis(vq, 1, 2).reshape(-1, hkv, half)
-        self.k_pool = self.k_pool.at[layer_slot, pages, offs].set(kq)
-        self.v_pool = self.v_pool.at[layer_slot, pages, offs].set(vq)
+        self.k_pool = self.k_pool.at[layer_slot, pages, :, offs].set(kq)
+        self.v_pool = self.v_pool.at[layer_slot, pages, :, offs].set(vq)
 
     def append_tokens(self, layer_slot: int, seq_ids, k, v, positions=None):
         """Batched one-token append: k/v ``[B, 1, Hkv, D]`` float, one
@@ -752,10 +752,10 @@ class PagedKV4Cache:
         tables = jnp.asarray(
             np.where(self.block_table[seq_ids, :npages] < 0, 0,
                      self.block_table[seq_ids, :npages]))
-        kp = self.k_pool[layer_slot][tables]    # [B, npages, ps, Hkv, D/2]
+        kp = self.k_pool[layer_slot][tables]    # [B, npages, Hkv, ps, D/2]
         vp = self.v_pool[layer_slot][tables]
-        b = kp.shape[0]
-        kp = kp.reshape(b, npages * ps, *kp.shape[3:]).swapaxes(1, 2)
-        vp = vp.reshape(b, npages * ps, *vp.shape[3:]).swapaxes(1, 2)
+        b, hkv = kp.shape[0], kp.shape[2]
+        kp = jnp.moveaxis(kp, 2, 1).reshape(b, hkv, npages * ps, -1)
+        vp = jnp.moveaxis(vp, 2, 1).reshape(b, hkv, npages * ps, -1)
         lengths = jnp.asarray(self.seq_len[seq_ids])
         return kp[:, :, :max_len], vp[:, :, :max_len], lengths

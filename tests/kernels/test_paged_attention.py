@@ -30,9 +30,9 @@ def make_paged(rng, b, hkv, d, ps, lengths, num_pages=None):
     need = sum((int(l) + ps - 1) // ps for l in lengths)
     num_pages = num_pages or need + 3
     k_pool = jnp.asarray(
-        rng.integers(0, 256, size=(num_pages, ps, hkv, d // 2)), jnp.uint8)
+        rng.integers(0, 256, size=(num_pages, hkv, ps, d // 2)), jnp.uint8)
     v_pool = jnp.asarray(
-        rng.integers(0, 256, size=(num_pages, ps, hkv, d // 2)), jnp.uint8)
+        rng.integers(0, 256, size=(num_pages, hkv, ps, d // 2)), jnp.uint8)
     tbl = np.full((b, npages), -1, np.int32)
     perm = rng.permutation(num_pages)
     i = 0
@@ -174,7 +174,7 @@ def test_prefill_zero_history_is_causal_flash(rng, impl):
     q = jnp.asarray(rng.normal(size=(b, c, hq, d)), jnp.float32)
     kn = jnp.asarray(rng.normal(size=(b, c, hkv, d)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(b, c, hkv, d)), jnp.float32)
-    kp = jnp.zeros((1, ps, hkv, d // 2), jnp.uint8)
+    kp = jnp.zeros((1, hkv, ps, d // 2), jnp.uint8)
     ks = jnp.ones((hkv, 1, d), jnp.float32)
     kz = jnp.zeros((hkv, 1, d), jnp.float32)
     o = ops.paged_kv4_prefill_attention(
@@ -218,8 +218,8 @@ def test_prefill_last_row_matches_decode(rng):
     tbl_np = np.asarray(tbl)
     for bi, l in enumerate(lengths):
         page, off = tbl_np[bi, l // ps], l % ps
-        kp_np[page, off] = pk[bi]
-        vp_np[page, off] = pv[bi]
+        kp_np[page, :, off] = pk[bi]
+        vp_np[page, :, off] = pv[bi]
     o_dec = ops.paged_kv4_decode_attention(
         q[:, 0], jnp.asarray(kp_np), ks, kz, jnp.asarray(vp_np), vs, vz,
         tbl, jnp.asarray([l + 1 for l in lengths], jnp.int32),
@@ -283,7 +283,7 @@ def test_wq_prefill_zero_history_is_causal_flash(rng, impl):
     q = jnp.asarray(rng.normal(size=(b, c, hq, d)), jnp.float32)
     kn = jnp.asarray(rng.normal(size=(b, c, hkv, d)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(b, c, hkv, d)), jnp.float32)
-    kp = jnp.zeros((1, ps, hkv, d // 2), jnp.uint8)
+    kp = jnp.zeros((1, hkv, ps, d // 2), jnp.uint8)
     ks = jnp.ones((hkv, 1, d), jnp.float32)
     kz = jnp.zeros((hkv, 1, d), jnp.float32)
     desc = build_work_queue(np.zeros((b, 1), np.int32), [0, 0], ps, hkv,

@@ -79,7 +79,10 @@ def test_zeroext_ref_gemm_random_splits(m, nb4, nb8, nblk_n, seed):
         ([a4i.reshape(m, -1, BK) * s4[:, :, None]] if k4 else
          [a8.reshape(m, -1, BK) * s8[:, :, None]]), axis=1).reshape(m, -1)
     wd = (wi.reshape(-1, BK, n) * ws[:, None, :]).reshape(-1, n)
-    np.testing.assert_allclose(out, ad @ wd, rtol=1e-5, atol=1e-5)
+    # f64 reference: an f32 numpy matmul's own rounding under
+    # cancellation (~3e-5 seen) would exceed the tolerance by itself
+    exact = ad.astype(np.float64) @ wd.astype(np.float64)
+    np.testing.assert_allclose(out, exact, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("nb4,nb8", [(1, 0), (0, 1), (2, 1), (1, 2)])

@@ -98,25 +98,25 @@ def test_maybe_shard_off_mesh_is_identity():
 
 def test_cache_pspecs_paged_pool_layout():
     """The serving engine's live pools: ONLY the kv-head dim shards
-    (dim 3 of [L, P, ps, Hkv, D/2]) — pages are a host-global namespace
+    (dim 2 of [L, P, Hkv, ps, D/2]) — pages are a host-global namespace
     — and the static per-channel scales [Hkv, 1, D] shard to match.
     Head counts not dividing the model axis fall back to replicated."""
     mesh = fake_mesh((2, 2), ("data", "model"))
     cache = {
-        "k_pool": np.zeros((2, 16, 8, 2, 16), np.uint8),
-        "v_pool": np.zeros((2, 16, 8, 2, 16), np.uint8),
+        "k_pool": np.zeros((2, 16, 2, 8, 16), np.uint8),
+        "v_pool": np.zeros((2, 16, 2, 8, 16), np.uint8),
         "k_scale": np.zeros((2, 1, 32), np.float32),
         "k_zero": np.zeros((2, 1, 32), np.float32),
         "v_scale": np.zeros((2, 1, 32), np.float32),
         "v_zero": np.zeros((2, 1, 32), np.float32),
     }
     specs = SH.cache_pspecs(cache, mesh)
-    assert specs["k_pool"] == P(None, None, None, "model", None)
-    assert specs["v_pool"] == P(None, None, None, "model", None)
+    assert specs["k_pool"] == P(None, None, "model", None, None)
+    assert specs["v_pool"] == P(None, None, "model", None, None)
     for name in ("k_scale", "k_zero", "v_scale", "v_zero"):
         assert specs[name] == P("model", None, None)
     # 3 kv heads on a 2-wide model axis → whole pool replicated
-    odd = {"k_pool": np.zeros((2, 16, 8, 3, 16), np.uint8),
+    odd = {"k_pool": np.zeros((2, 16, 3, 8, 16), np.uint8),
            "k_scale": np.zeros((3, 1, 32), np.float32)}
     specs_odd = SH.cache_pspecs(odd, mesh)
     assert specs_odd["k_pool"] == P(None, None, None, None, None)

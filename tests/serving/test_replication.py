@@ -235,3 +235,55 @@ def test_group_validates_arguments(setup):
     with pytest.raises(ValueError, match="one injector per replica"):
         ReplicaGroup(cfg, qparams, qc, ecfg, replicas=2,
                      faults=[FaultInjector()])
+
+
+def test_replicas_each_own_a_device_subprocess():
+    """model=1 replicas on a 4-device host: every replica's params and
+    KV pools sit on its own device (none doubled up on device 0), and
+    requests routed to each replica still finish. Runs on 4 forced CPU
+    devices in a child process (the device count is fixed at start-up)."""
+    import os
+    import subprocess
+    import sys
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import warnings; warnings.filterwarnings("ignore")
+import jax, numpy as np
+from repro.configs.base import get_smoke_config
+from repro.launch.mesh import make_replica_meshes
+from repro.models.lm import LM, QuantConfig
+from repro.serving.api import SamplingParams
+from repro.serving.engine import EngineConfig
+from repro.serving.replication import ReplicaGroup
+
+cfg = get_smoke_config("llama3_8b")
+qc = QuantConfig(impl="ref")
+qparams, qaxes = LM(cfg, quant=qc).init_quantized(jax.random.PRNGKey(0))
+group = ReplicaGroup(cfg, qparams, qc,
+                     EngineConfig(max_batch=4, num_pages=32, page_size=8),
+                     replicas=4, meshes=make_replica_meshes(4, 1),
+                     param_axes=qaxes)
+devs = []
+for rep in group.replicas:
+    eng = rep.engine
+    leaf_devs = {d for a in jax.tree.leaves(eng.params) for d in a.devices()}
+    pool_devs = eng.cache.k_pool.devices() | eng.cache.v_pool.devices()
+    assert len(leaf_devs) == 1 and leaf_devs == pool_devs, (leaf_devs, pool_devs)
+    devs.append(leaf_devs.pop())
+assert len(set(devs)) == 4, devs
+rng = np.random.default_rng(0)
+for _ in range(4):
+    group.submit(rng.integers(1, 100, 12).tolist(),
+                 SamplingParams(max_new_tokens=3))
+group.run()
+assert group.internal_errors == 0
+assert all(r.engine.failed_count == 0 for r in group.replicas)
+assert sum(len(v) for v in group.delivered.values()) == 12
+assert all(r.engine.forward_calls > 0 for r in group.replicas)
+print("OK")
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, env=env)
+    assert "OK" in out.stdout, out.stderr[-2000:]
