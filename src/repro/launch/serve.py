@@ -39,7 +39,10 @@ routing, per-step health checks, RecoveryLog artifact shipping —
 with ``--failover standby|migrate`` picking the death policy and
 ``--kill-replica-at STEP`` (``--kill-replica IDX``) arming the
 deterministic ``crash`` fault for failover smokes; the ``[group]``
-summary line reports failovers/migrations/health. The
+summary line reports failovers/migrations/health. ``--profile-dir DIR``
+runs the single-engine serving loop under ``jax.profiler.start_trace(DIR)``:
+each step is an ``Engine.step`` span holding the engine's step-phase
+spans (serving/tracing.py), on the clock of the device ops. The
 end-of-run summary reports throughput, prefix-cache hit rate + eviction
 counters, schedule work/grid counters (per shard under TP), lifecycle
 counts (aborted/failed/timed-out/shed/rejected), and the fired faults.
@@ -53,6 +56,8 @@ Usage:
   XLA_FLAGS=--xla_force_host_platform_device_count=2 \
       PYTHONPATH=src python -m repro.launch.serve --arch llama3_8b \
       --smoke --mesh 1x2 --head-dim 64 --int4-fraction 1.0
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3_8b --smoke \
+      --requests 6 --max-new 12 --profile-dir /tmp/serve_profile
 """
 
 from __future__ import annotations
@@ -69,6 +74,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, parse_mesh_arg
 from repro.models.lm import LM, QuantConfig
 from repro.serving.engine import Engine, EngineConfig, SamplingParams
+from repro.serving.tracing import span
 
 
 def _ms_stats(xs: list) -> str:
@@ -331,7 +337,13 @@ def main():
                          "128-channel quant blocks) — pass 64 with "
                          "--smoke --mesh 1x2")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--profile-dir", default="",
+                    help="write a jax.profiler trace of the serving loop "
+                         "here, with the engine's step-phase spans on "
+                         "(single engine only)")
     args = ap.parse_args()
+    if args.profile_dir and args.replicas > 1:
+        ap.error("--profile-dir profiles one engine; drop --replicas")
     enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
@@ -401,6 +413,10 @@ def main():
     abort_ids: set = set()
     submitted = 0
 
+    profiling = bool(args.profile_dir)
+    if profiling:
+        eng.trace_spans = True
+        jax.profiler.start_trace(args.profile_dir)
     t0 = time.time()
     while (pending or eng.sched.has_work) and eng.steps < 10_000:
         while pending and pending[0][0] <= eng.steps:
@@ -409,11 +425,12 @@ def main():
             submitted += 1
             if args.abort_every and submitted % args.abort_every == 0:
                 abort_ids.add(h.request_id)
-        if log is not None:
-            evs = log.step()
-        else:
-            eng.step()
-            evs = eng.events()
+        with span("Engine.step", profiling):
+            if log is not None:
+                evs = log.step()
+            else:
+                eng.step()
+                evs = eng.events()
         for ev in evs:
             if ev.token is not None and ev.request_id in abort_ids:
                 eng.abort(ev.request_id)       # cancel after first token
@@ -429,6 +446,10 @@ def main():
                           + (f" ({ev.stop_reason})" if ev.stop_reason
                              else ""), flush=True)
     dt = time.time() - t0
+    if profiling:
+        jax.profiler.stop_trace()
+        print(f"[profile] trace of {eng.steps} steps in {args.profile_dir}",
+              flush=True)
 
     finished = eng.sched.finished
     total_tokens = sum(len(r.generated) for r in finished)

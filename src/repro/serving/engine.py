@@ -166,6 +166,12 @@ unexpected exception anywhere else in the step is swallowed into
 everything. These guards cover the unified step (the default); the
 split/whole/gather fig11 baselines rely on the outer backstop only.
 
+**Step-phase spans.** With ``trace_spans`` set, each unified step
+marks its phases (serving/tracing.py) as ``jax.profiler`` spans on the
+profiler's clock, so a device trace shows what the host was doing while
+the device idled. Admission stamps ``Request.admitted_at`` once, beside
+``arrived_at`` and ``first_token_at``.
+
 Crash recovery: ``snapshot()`` captures scheduler state; ``Engine.
 restore`` rebuilds mid-flight work after a crash (prompts re-prefill
 from ``prefill_pos=0`` — partial prefill is device KV, lost with the
@@ -215,6 +221,7 @@ from repro.serving.kv_cache import PagedKV4Cache, PagedKV4Config
 from repro.serving.sanitize import check_engine
 from repro.serving.scheduler import Request, Scheduler
 from repro.serving.speculation import DraftSource, PromptLookupDraft
+from repro.serving.tracing import Phases
 
 __all__ = ["Engine", "EngineConfig", "SamplingParams", "RequestState",
            "RequestOutput", "RequestHandle"]
@@ -410,7 +417,8 @@ class Engine:
         "lm", "params", "donate_pools", "_fwd", "_fwd_shapes",
         "_sample_fns", "_gather_bcast", "_param_pspecs", "_scale_pspec",
         "_pool_pspec", "tp_size",
-        "_events", "draft_source",
+        "_events", "draft_source", "trace_spans", "_phases",
+        "last_forward_rows",
         # per-process observability counters
         "peak_prefill_fp_tokens", "interleaved_steps", "forward_calls",
         "trace_count", "prefix_hit_tokens", "prefill_tokens",
@@ -536,6 +544,15 @@ class Engine:
         # fig11 sharded part asserts (single device: one entry, equal
         # to attn_work_items)
         self.attn_work_items_per_shard = [0] * self.tp_size
+        # step-phase profiler spans (serving/tracing.py), off by default:
+        # while off, each phase boundary costs one check of trace_spans;
+        # while on, last_forward_rows keeps the last forward's
+        # [(ctx, qlen)] rows
+        self.trace_spans = False
+        self._phases = Phases()
+        self.last_forward_rows: list = []
+        # forward shapes this engine has run (unified: the jit bucket
+        # key; split: the eager dispatch signature)
         self._fwd_shapes: set = set()
         self._gather_bcast: dict = {}      # bsz → broadcast scales/zeros
         # donate the pool buffers so the traced KV scatter updates them
@@ -955,11 +972,15 @@ class Engine:
         except Exception as e:  # noqa: BLE001 — the serving-loop backstop
             self.internal_errors += 1
             self.last_error = repr(e)
+        if self.trace_spans:
+            self._phases.end()
         if self.ecfg.sanitize:
             check_engine(self)
             self.sanitize_checks += 1
 
     def _step_inner(self):
+        if self.trace_spans and self.ecfg.unified:
+            self._phases.enter("engine.schedule")
         # deadline/TTFT expiry runs BEFORE admission: a dead-on-arrival
         # request must never acquire pages just to be torn down
         for req in self.sched.expire_deadlines(self.cache, self.clock()):
@@ -972,6 +993,9 @@ class Engine:
             first_chunk_tokens=(self.ecfg.prefill_chunk_tokens if chunked
                                 else None),
             prefix_cache=self.ecfg.prefix_caching)
+        for r in admitted:
+            if not r.admitted_at:   # a preempted request keeps its stamp
+                r.admitted_at = self.clock()
         # admission-time rejections (prompt_too_long) reach finished
         # without passing through _complete — they still owe their
         # terminal event
@@ -1236,6 +1260,8 @@ class Engine:
         whose logits are NaN/Inf (a spec row checks its whole verify
         chunk), and the sampler/verifier run under their own guards
         (rows mid-prefill are never touched by either)."""
+        if self.trace_spans:
+            self._phases.enter("engine.pack")
         rows = list(plan) + [
             (r, int(self.cache.seq_len[r.seq_slot]), 1 + len(d))
             for r, d in decode]
@@ -1278,6 +1304,10 @@ class Engine:
             for r, _, _ in rows:
                 self._fail(r, f"forward: {e!r}")
             return
+        if self.trace_spans:
+            self._phases.enter("engine.sample")
+            self.last_forward_rows = list(zip(starts.tolist(),
+                                              takes.tolist()))
 
         # host state: prompt progress + decode appends; a completed
         # prompt publishes its full pages into the prefix index.
@@ -1492,15 +1522,16 @@ class Engine:
             self.attn_dense_grid_items += nb * hkv * (npb + 1)
             self.attn_grid_items += (desc_np.shape[0] * self.tp_size if wq
                                      else nb * hkv * (npb + 1))
+        lb = _bucket(len(logit_idx))
         nan_fault = None
         fwd_fault = self.faults.check("forward")
         if fwd_fault is not None:
             if fwd_fault.action == "raise":
                 raise InjectedFault("forward: injected forward failure")
             nan_fault = fwd_fault
-        logits, k_pool, v_pool = self._fwd(
-            cb, no_history, schedule, self.params, self.cache.k_pool,
-            self.cache.v_pool,
+        if self.trace_spans:
+            self._phases.enter("engine.upload")
+        layout = (
             jnp.asarray(_pad_to(tokens, tb)),
             jnp.asarray(_pad_to(tok_pos, tb)),
             # padding destinations: page == num_pages is out of range →
@@ -1520,11 +1551,25 @@ class Engine:
             jnp.asarray(_pad_to(takes, nb)),           # qlens per row
             # consumed logit slots, own bucket (== nb when nothing
             # speculates — the historical last-token-per-row layout)
-            jnp.asarray(_pad_to(logit_idx, _bucket(len(logit_idx)))),
-            jnp.asarray(desc_np),                      # wq work items
+            jnp.asarray(_pad_to(logit_idx, lb)),
+            jnp.asarray(desc_np))                      # wq work items
+        # the jit-cache key: the statics and every padded size
+        bucket = (cb, no_history, schedule, tb, nb, tables.shape[1],
+                  desc_np.shape[0], lb)
+        new_bucket = bucket not in self._fwd_shapes
+        if new_bucket:
+            self._fwd_shapes.add(bucket)
+        if self.trace_spans:
+            self._phases.enter("engine.dispatch.new_bucket" if new_bucket
+                               else "engine.dispatch")
+        logits, k_pool, v_pool = self._fwd(
+            cb, no_history, schedule, self.params, self.cache.k_pool,
+            self.cache.v_pool, *layout,
             self.cache.k_scale, self.cache.k_zero,
             self.cache.v_scale, self.cache.v_zero)
         self.cache.k_pool, self.cache.v_pool = k_pool, v_pool
+        if self.trace_spans:
+            self._phases.enter("engine.readback")
         # np.array (copy): the device buffer view is read-only and the
         # caller mutates rows in place (nan injection)
         return np.array(logits), nan_fault

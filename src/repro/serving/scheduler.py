@@ -67,6 +67,9 @@ class Request:
     prompt: list                   # token ids
     max_new_tokens: int
     arrived_at: float = 0.0
+    admitted_at: float = 0.0       # clock at first admission (kept across
+    #                                preemptions): queue wait is
+    #                                admitted_at - arrived_at
     generated: list = dataclasses.field(default_factory=list)
     seq_slot: int = -1             # cache slot when running
     prefill_pos: int = 0           # prompt tokens already through the model
@@ -406,6 +409,7 @@ class Scheduler:
             "generated": list(r.generated),
             "max_new_tokens": r.max_new_tokens,
             "arrived_at": r.arrived_at,
+            "admitted_at": r.admitted_at,
             "first_token_at": r.first_token_at,
             "finished_at": r.finished_at,
             "cached_tokens": r.cached_tokens,
@@ -427,6 +431,7 @@ class Scheduler:
             request_id=e["request_id"], prompt=list(e["prompt"]),
             max_new_tokens=e["max_new_tokens"],
             arrived_at=e.get("arrived_at", 0.0),
+            admitted_at=e.get("admitted_at", 0.0),
             first_token_at=e.get("first_token_at", 0.0),
             finished_at=e.get("finished_at", 0.0),
             cached_tokens=e.get("cached_tokens", 0),
@@ -471,6 +476,7 @@ class Scheduler:
                 "prompt": list(r.prompt) + list(r.generated),
                 "max_new_tokens": r.max_new_tokens - len(r.generated),
                 "arrived_at": r.arrived_at,
+                "admitted_at": r.admitted_at,
                 # TTFT / prefix-hit accounting must survive the restart:
                 # a request that already produced its first token keeps
                 # its stamp (restore must not re-measure TTFT against
@@ -490,6 +496,7 @@ class Scheduler:
             "stop_reason": r.stop_reason,
             "state": r.state.value,
             "arrived_at": r.arrived_at,
+            "admitted_at": r.admitted_at,
             "first_token_at": r.first_token_at,
             "cached_tokens": r.cached_tokens,
             "emitted": r.emitted,
@@ -519,6 +526,7 @@ class Scheduler:
                 request_id=r["request_id"], prompt=r["prompt"],
                 max_new_tokens=r["max_new_tokens"],
                 arrived_at=r["arrived_at"],
+                admitted_at=r.get("admitted_at", 0.0),
                 first_token_at=r.get("first_token_at", 0.0),
                 cached_tokens=r.get("cached_tokens", 0),
                 emitted=r.get("emitted", 0),
@@ -526,7 +534,8 @@ class Scheduler:
         for r in state["finished"]:
             req = Request(request_id=r["request_id"], prompt=r["prompt"],
                           max_new_tokens=0,
-                          arrived_at=r.get("arrived_at", 0.0))
+                          arrived_at=r.get("arrived_at", 0.0),
+                          admitted_at=r.get("admitted_at", 0.0))
             req.generated = r["generated"]
             req.stop_reason = r.get("stop_reason")
             req.state = RequestState(r.get("state", "finished"))
