@@ -458,7 +458,8 @@ def main():
     print(f"[done] {len(finished)} requests, {total_tokens} tokens in "
           f"{dt:.1f}s → {total_tokens/dt:.1f} tok/s "
           f"(steps={eng.steps}, forwards={eng.forward_calls}, "
-          f"traces={eng.trace_count}, preemptions={eng.sched.preemptions})",
+          f"uploads={eng.host_uploads}, traces={eng.trace_count}, "
+          f"preemptions={eng.sched.preemptions})",
           flush=True)
     print(f"[cache] prefix hit rate {hit_rate:.0%} "
           f"({eng.prefix_hit_tokens}/{prompt_tokens} prompt tokens served "

@@ -195,6 +195,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import json
+import math
 import time
 from typing import Optional
 
@@ -277,6 +278,42 @@ def _pad_to(a: np.ndarray, n: int, fill=0) -> np.ndarray:
     out = np.full((n,), fill, np.int32)
     out[: len(a)] = a
     return out
+
+
+def _layout_shapes(tb: int, nb: int, npb: int, lb: int, wb: int) -> tuple:
+    """Shapes of the unified forward's 12 int32 layout arrays, in the
+    order :meth:`Engine._unified_forward` takes them: tokens, positions,
+    pages, offs, tseq, toff and the dq mask ``[Tb]``; the block tables
+    ``[Nb, NPb]``; ctx and qlens ``[Nb]``; the logit slots ``[Lb]``; the
+    work-queue descriptors ``[Wb, 4]``."""
+    return ((tb,),) * 7 + ((nb, npb), (nb,), (nb,), (lb,), (wb, 4))
+
+
+def _pack_layout(arrays, shapes, fills) -> np.ndarray:
+    """Lay ``arrays`` back to back into one 1-D int32 buffer, so the whole
+    layout crosses to the device in one transfer: each array fills the
+    front of its segment (sized by ``shapes``) and its ``fills`` entry the
+    rest. An array longer than its segment raises."""
+    buf = np.empty(sum(math.prod(s) for s in shapes), np.int32)
+    o = 0
+    for a, shape, fill in zip(arrays, shapes, fills):
+        n = math.prod(shape)
+        seg, a = buf[o:o + n], np.ravel(a)
+        seg[:a.size] = a
+        seg[a.size:] = fill
+        o += n
+    return buf
+
+
+def _unpack_layout(buf, shapes) -> tuple:
+    """Slice a :func:`_pack_layout` buffer back into its arrays, at offsets
+    fixed by ``shapes`` (the engine jits this with ``shapes`` static)."""
+    out, o = [], 0
+    for shape in shapes:
+        n = math.prod(shape)
+        out.append(buf[o:o + n].reshape(shape))
+        o += n
+    return tuple(out)
 
 
 def _row_linear(p, x, tp_axis):
@@ -415,13 +452,14 @@ class Engine:
     _SNAPSHOT_EXEMPT = frozenset({
         # rebuilt by __init__ / only meaningful in-process
         "lm", "params", "donate_pools", "_fwd", "_fwd_shapes",
+        "_unpack", "_layout_sharding",
         "_sample_fns", "_gather_bcast", "_param_pspecs", "_scale_pspec",
         "_pool_pspec", "tp_size",
         "_events", "draft_source", "trace_spans", "_phases",
         "last_forward_rows",
         # per-process observability counters
         "peak_prefill_fp_tokens", "interleaved_steps", "forward_calls",
-        "trace_count", "prefix_hit_tokens", "prefill_tokens",
+        "host_uploads", "trace_count", "prefix_hit_tokens", "prefill_tokens",
         "aborted_count", "failed_count", "timeout_count", "shed_count",
         "rejected_count", "callback_errors", "internal_errors",
         "last_error", "sanitize_checks", "attn_work_items",
@@ -490,12 +528,16 @@ class Engine:
         # by prefill_chunk_tokens in chunked mode), steps that ran
         # prefill and decode work back-to-back (interleave evidence for
         # fig11), model forwards issued (unified: exactly one per step
-        # with work), and distinct compiled forward variants (unified:
-        # real jit traces, counted inside the traced body; split: one per
-        # new packed-shape signature — the eager dispatch cache key)
+        # with work), host→device transfers those forwards issued
+        # (unified: one packed layout each, so equal to forward_calls;
+        # the split baseline's eager uploads are not counted), and
+        # distinct compiled forward variants (unified: real jit traces,
+        # counted inside the traced body; split: one per new
+        # packed-shape signature — the eager dispatch cache key)
         self.peak_prefill_fp_tokens = 0
         self.interleaved_steps = 0
         self.forward_calls = 0
+        self.host_uploads = 0
         self.trace_count = 0
         # prefix-cache + lifecycle counters: prompt tokens served from
         # published pages vs actually forwarded, and aborted requests
@@ -577,6 +619,14 @@ class Engine:
             donate_argnums=(argnums_of(self._unified_forward,
                                        *self._FWD_DONATE_ARGS)
                             if self.donate_pools else ()))
+        # the forward's layout crosses to the device as ONE packed int32
+        # buffer (each transfer has a fixed cost that dwarfs its few KB),
+        # put straight onto the mesh replicated — the sharding every
+        # layout array enters the forward with — and sliced back into the
+        # forward's arguments by a jitted prologue, one dispatch per step
+        self._layout_sharding = (NamedSharding(mesh, P())
+                                 if mesh is not None else None)
+        self._unpack = jax.jit(_unpack_layout, static_argnames="shapes")
         self._sample_fns: dict = {}        # kmax → jitted batched sampler
         self._by_id: dict[int, Request] = {}
         self._next_id = 0
@@ -1463,10 +1513,15 @@ class Engine:
         token indices whose logits the caller consumes (one per row
         unless a row speculates, then all its chunk positions); its own
         bucket ``lb`` joins the jit-cache key, and collapses to the
-        historical ``nb`` whenever no row speculates. Returns (writable
-        logits ndarray [lb, V], nan_fault). No host scheduler/cache
-        bookkeeping moves in here — an exception leaves page accounting
-        untouched, so the caller's quarantine frees back to baseline."""
+        historical ``nb`` whenever no row speculates. The 12 layout
+        arrays are written into one int32 buffer (:func:`_pack_layout`)
+        and cross to the device in ONE transfer (``host_uploads``); the
+        jitted :func:`_unpack_layout` slices them back out for
+        :meth:`_unified_forward`, whose arguments are unchanged.
+        Returns (writable logits ndarray [lb, V], nan_fault). No host
+        scheduler/cache bookkeeping moves in here — an exception leaves
+        page accounting untouched, so the caller's quarantine frees back
+        to baseline."""
         pages_np, offs_np = self.cache.token_dests_np(slots[tok_seq],
                                                       tok_pos)
         # shape buckets — the jit cache key
@@ -1531,28 +1586,27 @@ class Engine:
             nan_fault = fwd_fault
         if self.trace_spans:
             self._phases.enter("engine.upload")
-        layout = (
-            jnp.asarray(_pad_to(tokens, tb)),
-            jnp.asarray(_pad_to(tok_pos, tb)),
+        shapes = _layout_shapes(tb, nb, tables.shape[1], lb,
+                                desc_np.shape[0])
+        packed = _pack_layout(
+            (tokens, tok_pos, pages_np, offs_np, tok_seq, tok_off,
+             # decode tokens (the packed tail) fake-quantize their
+             # in-flight KV so self-attention matches the int4 the split
+             # decode path reads back
+             np.arange(ttot) >= cum[len(plan)],
+             tables,
+             starts, takes,                            # ctx, qlens per row
+             # consumed logit slots, own bucket (== nb when nothing
+             # speculates — the historical last-token-per-row layout)
+             logit_idx,
+             desc_np),                                 # wq work items
+            shapes,
             # padding destinations: page == num_pages is out of range →
             # the scatter update is dropped; row == nb likewise drops in
-            # the packed→padded scatter
-            jnp.asarray(_pad_to(pages_np, tb,
-                                fill=self.cache.pcfg.num_pages)),
-            jnp.asarray(_pad_to(offs_np, tb)),
-            jnp.asarray(_pad_to(tok_seq, tb, fill=nb)),
-            jnp.asarray(_pad_to(tok_off, tb)),
-            # decode tokens (the packed tail) fake-quantize their
-            # in-flight KV so self-attention matches the int4 the split
-            # decode path reads back
-            jnp.asarray(_pad_to(np.arange(ttot) >= cum[len(plan)], tb)),
-            jnp.asarray(tables),
-            jnp.asarray(_pad_to(starts, nb)),          # ctx per row
-            jnp.asarray(_pad_to(takes, nb)),           # qlens per row
-            # consumed logit slots, own bucket (== nb when nothing
-            # speculates — the historical last-token-per-row layout)
-            jnp.asarray(_pad_to(logit_idx, lb)),
-            jnp.asarray(desc_np))                      # wq work items
+            # the packed→padded scatter; everything else pads with 0
+            fills=(0, 0, self.cache.pcfg.num_pages, 0, nb) + (0,) * 7)
+        layout = jax.device_put(packed, self._layout_sharding)
+        self.host_uploads += 1
         # the jit-cache key: the statics and every padded size
         bucket = (cb, no_history, schedule, tb, nb, tables.shape[1],
                   desc_np.shape[0], lb)
@@ -1564,7 +1618,7 @@ class Engine:
                                else "engine.dispatch")
         logits, k_pool, v_pool = self._fwd(
             cb, no_history, schedule, self.params, self.cache.k_pool,
-            self.cache.v_pool, *layout,
+            self.cache.v_pool, *self._unpack(layout, shapes=shapes),
             self.cache.k_scale, self.cache.k_zero,
             self.cache.v_scale, self.cache.v_zero)
         self.cache.k_pool, self.cache.v_pool = k_pool, v_pool
@@ -1598,7 +1652,11 @@ class Engine:
         pools enter pre-sharded (placed by ``_init_sharding``), every
         int32 layout array is replicated (page ids are host-global), and
         each shard computes its kv-head slice end to end with psums only
-        at the wo / w_down seams (inside ``_row_linear``)."""
+        at the wo / w_down seams (inside ``_row_linear``).
+
+        The 12 layout arrays are slices of the step's one packed upload
+        (``_guarded_forward``), already on the device — replicated over
+        the mesh when the engine has one."""
         self.trace_count += 1          # traced body: fires once per compile
         args = (params, k_pool, v_pool, tokens, positions, pages, offs,
                 tseq, toff, dq_mask, block_tables, ctx, qlens, last_idx,

@@ -14,9 +14,11 @@ forward of a shape bucket this engine has not run before is
   reservation, speculation planning and the prefill plan;
 * ``engine.pack``: the host layout of the forward (destinations, shape
   buckets, work-queue descriptors, block tables, attention counters);
-* ``engine.upload``: the host-to-device copies of that layout;
-* ``engine.dispatch`` / ``engine.dispatch.new_bucket``: the jitted
-  forward's call, which lowers and compiles a new bucket;
+* ``engine.upload``: that layout written into one int32 buffer and its
+  one host-to-device copy;
+* ``engine.dispatch`` / ``engine.dispatch.new_bucket``: the calls of the
+  jitted slicing of that buffer and of the jitted forward, which lower
+  and compile a new bucket;
 * ``engine.readback``: the logits' copy back to the host, where the host
   waits for the forward;
 * ``engine.sample``: host state, the finite guard, sampling,

@@ -156,6 +156,29 @@ def test_sharded_speculation_parity(model):
         assert eng.internal_errors == 0
 
 
+def test_sharded_one_upload_per_forward(model, monkeypatch):
+    """Under TP each forward's layout crosses in ONE transfer, put
+    straight onto the mesh replicated (the sharding the layout enters
+    ``shard_map`` with)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = make_local_mesh(1, TP)
+    eng = _engine(model, mesh)
+    puts = []
+    real = jax.device_put
+
+    def spy(x, *a, **k):
+        puts.append(a[0] if a else k.get("device"))
+        return real(x, *a, **k)
+
+    monkeypatch.setattr(jax, "device_put", spy)
+    for i, p in enumerate(_prompts((11, 19, 7))):
+        eng.add_request(i, p, 6)
+    eng.run(max_steps=300)
+    assert eng.internal_errors == 0
+    assert eng.host_uploads == eng.forward_calls == eng.steps == len(puts)
+    assert all(s == NamedSharding(mesh, P()) for s in puts)
+
+
 def test_sharded_requires_param_axes(model):
     """mesh without param_axes cannot place weights — loud error, not
     a silently replicated (wrong-counter) engine."""
